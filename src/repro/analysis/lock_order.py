@@ -56,6 +56,7 @@ RANKS: Dict[str, int] = {
     "busy": 70,
     "timers": 70,
     "history": 70,
+    "trainer": 70,  # the train step's donated optimizer state
     # condition locks
     "gate": 90,  # EventGate
     "ps": 90,  # ReadWriteLock (parameter server)
@@ -67,7 +68,7 @@ ORDER_KEYED = frozenset({"instance"})
 
 #: Hard leaves: nothing may be acquired while one of these is held.
 TERMINAL = frozenset(
-    {"metric", "stats", "busy", "timers", "history", "gate", "ps"}
+    {"metric", "stats", "busy", "timers", "history", "trainer", "gate", "ps"}
 )
 
 #: Condition-lock names (RPL005: notify must hold exactly its own lock).

@@ -19,12 +19,8 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.5 exports it at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
 
 
 def _quantize(g: jax.Array) -> Tuple[jax.Array, jax.Array]:

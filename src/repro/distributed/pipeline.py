@@ -23,19 +23,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.5 exports it at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
-
-
-def _pvary(x, axes):
-    """Mark ``x`` as varying over ``axes`` for shard_map's rep typing.
-    jax 0.4.x has no ``lax.pvary`` (and no varying-axis check) — identity."""
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axes) if fn is not None else x
 
 
 def gpipe_apply(
@@ -79,8 +68,8 @@ def gpipe_apply(
             return buf, outs
 
         # initial carries must be marked stage-varying for shard_map typing
-        buf0 = _pvary(jnp.zeros_like(xs[0]), (stage_axis,))
-        outs0 = _pvary(jnp.zeros_like(xs), (stage_axis,))
+        buf0 = jax.lax.pcast(jnp.zeros_like(xs[0]), stage_axis, to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(xs), stage_axis, to="varying")
         _, outs = jax.lax.fori_loop(0, ticks, tick, (buf0, outs0))
         # outputs live on the last stage only; sum across stages replicates
         return jax.lax.psum(outs, stage_axis)

@@ -3,10 +3,26 @@
 Defined as FUNCTIONS (never module-level constants) so importing this
 module does not touch jax device state — the dry-run must set XLA_FLAGS
 before first jax init.
+
+Every mesh here has ``Auto`` axes. ``jax.make_mesh`` defaults to
+``Explicit`` axes, under which shardings become part of array types and
+ops such as ``dynamic_update_slice`` reject operands whose shardings
+differ; the model code instead places arrays with sharding constraints
+and lets the compiler propagate them, which is what ``Auto`` axes mean.
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with every axis ``Auto`` (see the module doc)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,12 +31,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     pure data parallelism over DCN."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1-device mesh for smoke-testing launcher code paths."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def make_rollout_mesh(n_shards: int):
@@ -43,4 +59,4 @@ def make_rollout_mesh(n_shards: int):
             f"--xla_force_host_platform_device_count={n_shards} before the "
             f"first jax call"
         )
-    return jax.make_mesh((n_shards,), ("tensor",))
+    return auto_mesh((n_shards,), ("tensor",))

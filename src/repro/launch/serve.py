@@ -13,6 +13,7 @@ switches the structured log to NDJSON.
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax
@@ -21,6 +22,7 @@ from repro.configs import get_arch
 from repro.core.types import Trajectory, next_traj_id
 from repro.data.tasks import ArithmeticDataset
 from repro.data.tokenizer import decode as tok_decode
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.obs import get_logger, setup_logging
 from repro.rollout.backend import create_backend
@@ -112,11 +114,17 @@ def main() -> None:
         "--log-json", action="store_true",
         help="structured NDJSON logs instead of human-readable lines",
     )
+    ap.add_argument(
+        "--wall-limit", type=float, default=120.0,
+        help="seconds of decoding before giving up; the run exits 1 if "
+             "any request is still unserved then (compilation counts)",
+    )
     args = ap.parse_args()
     if args.score_url or args.score_sandbox:
         args.score = True
     setup_logging(json_mode=args.log_json)
     log = get_logger("serve")
+    use_compile_cache()
 
     cfg = get_arch(args.arch).reduced()
     if args.kv_heads:
@@ -220,7 +228,7 @@ def main() -> None:
 
     t0 = time.time()
     done = []
-    while len(done) < n_requests and time.time() - t0 < 120:
+    while len(done) < n_requests and time.time() - t0 < args.wall_limit:
         s0 = time.perf_counter()
         finished = inst.step()
         if tracer is not None:
@@ -285,6 +293,16 @@ def main() -> None:
                 "spans": trace["otherData"]["spans"],
             },
         )
+    if len(done) < n_requests:
+        log.error(
+            "wall limit reached with requests unserved",
+            extra={
+                "served": len(done),
+                "requests": n_requests,
+                "wall_limit_s": args.wall_limit,
+            },
+        )
+        sys.exit(1)
 
 
 if __name__ == "__main__":

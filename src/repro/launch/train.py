@@ -25,12 +25,13 @@ import jax.numpy as jnp
 from repro.configs import get_arch
 from repro.distributed import sharding as shd
 from repro.distributed.collectives import make_dp_allreduce
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.obs import get_logger, setup_logging
 from repro.training import checkpoint as ckpt_lib
 from repro.training.optimizer import AdamWConfig, init_opt_state
-from repro.training.train_step import make_rl_train_step
+from repro.training.train_step import TRAINER_IMPL, make_rl_train_step
 
 
 def main() -> None:
@@ -60,6 +61,7 @@ def main() -> None:
     args = ap.parse_args()
     setup_logging(json_mode=args.log_json)
     log = get_logger("train")
+    use_compile_cache()
 
     tracer = None
     if args.trace:
@@ -101,7 +103,7 @@ def main() -> None:
     step = jax.jit(
         make_rl_train_step(
             cfg, AdamWConfig(lr=args.lr), remat=args.remat,
-            accum_steps=args.accum,
+            accum_steps=args.accum, impl=TRAINER_IMPL,
         ),
         in_shardings=(p_sh, o_sh, b_sh),
         out_shardings=(p_sh, o_sh, None),

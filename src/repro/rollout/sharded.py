@@ -27,13 +27,14 @@ accelerators, the way Laminar/AsyncFlow deploy multi-GPU rollout replicas.
   the traced model gathers activations to replicated form before any
   contraction would cross a sharded dimension (``ctx.gather``).
 
-Bitwise contract: no reduction is ever partitioned — attention is
+Equality contract: no reduction is ever partitioned — attention is
 per-head, softmax runs over the (unsharded) sequence axis, and every
-matmul contracts over a replicated dimension — so greedy decode is
-**bit-for-bit** equal to the single-device paged engine (tokens *and*
-behavior logprobs), across batched admission, CoW prefix sharing, and
-preemption. ``tests/test_sharded_backend.py`` pins this on 8 forced host
-devices.
+matmul contracts over a replicated dimension — so greedy decode matches
+the single-device paged engine, tokens exactly and behavior logprobs to
+float32 rounding (the partitioned program may group sums differently),
+across batched admission, CoW prefix sharing, and preemption.
+``tests/test_sharded_backend.py`` pins this on 8 forced host devices,
+``chip_smoke.py --four-chips`` on four TPU chips.
 
 Memory plane: ``kv_budget`` and every reported byte figure are
 *per-device* — the engine charges ``k5 / shard_count`` per token, and

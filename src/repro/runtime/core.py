@@ -69,7 +69,7 @@ from repro.rollout.backend import EngineBackend, create_backend, execute_command
 from repro.runtime.config import RuntimeConfig, StepRecord
 from repro.training import checkpoint as ckpt_lib
 from repro.training.optimizer import AdamWConfig, init_opt_state
-from repro.training.train_step import make_rl_train_step
+from repro.training.train_step import TRAINER_IMPL, make_rl_train_step
 
 
 class LockedBackend:
@@ -146,8 +146,17 @@ class RuntimeCore:
         key = jax.random.PRNGKey(rcfg.seed)
         self.params = M.init_params(cfg, key)
         self.opt_state = init_opt_state(self.params)
+        # only the optimizer state is donated: the parameter server and the
+        # rollout instances hold the same param arrays (stale versions
+        # stay alive up to eta), so params must survive the step. The
+        # trainer lock keeps checkpoint() from reading a donated state.
+        self._train_lock = make_lock("trainer")
         self.train_step = jax.jit(
-            make_rl_train_step(cfg, AdamWConfig(lr=rcfg.lr), objective=rcfg.objective)
+            make_rl_train_step(
+                cfg, AdamWConfig(lr=rcfg.lr), objective=rcfg.objective,
+                impl=TRAINER_IMPL,
+            ),
+            donate_argnums=(1,),
         )
 
         # ------------------------------------------------- the service bus
@@ -640,9 +649,10 @@ class RuntimeCore:
         batch = self._batch_from_trajs(trajs)
         if batch is None:
             return None
-        self.params, self.opt_state, metrics = self.train_step(
-            self.params, self.opt_state, batch
-        )
+        with self._train_lock:
+            self.params, self.opt_state, metrics = self.train_step(
+                self.params, self.opt_state, batch
+            )
         self.model_version += 1
         self._push_fn(self.params, self.model_version)
         t1 = time.perf_counter()
@@ -849,11 +859,15 @@ class RuntimeCore:
 
     # ------------------------------------------------------------ checkpoint
     def checkpoint(self, directory: str) -> str:
+        # host copies taken under the trainer lock: a threaded trainer's
+        # next step donates (frees) the optimizer state on the device
+        with self._train_lock:
+            params, opt_state = jax.device_get((self.params, self.opt_state))
         return ckpt_lib.save_checkpoint(
             directory,
             self.model_version,
-            self.params,
-            self.opt_state,
+            params,
+            opt_state,
             extra_meta={"model_version": self.model_version, "tick": self._tick},
             protocol_state=ckpt_lib.dump_service_state(
                 self.manager,

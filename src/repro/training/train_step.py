@@ -23,6 +23,10 @@ from repro.models import model as M
 from repro.rl import losses
 from repro.training.optimizer import AdamWConfig, adamw_update
 
+# Kernel implementation the trainer differentiates through: XLA's fused jnp
+# attention and loss, because the Pallas kernels have no backward pass.
+TRAINER_IMPL = "ref"
+
 
 def make_rl_train_step(
     cfg: ArchConfig,

@@ -75,10 +75,12 @@ def _run_subprocess(code: str, devices: int = 8) -> str:
             "PYTHONPATH": "src",
             "PATH": "/usr/bin:/bin",
             # without this the child jax probes for a TPU backend (libtpu
-            # ships in the image) and stalls minutes on metadata retries
+            # ships in the image) and stalls minutes on metadata retries.
+            # On a machine with a TPU it also keeps the child off the chip,
+            # which belongs to one process at a time.
             "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
         },
-        cwd="/root/repo",
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
     assert out.returncode == 0, out.stderr[-3000:]
     return out.stdout
@@ -107,7 +109,8 @@ def test_compressed_psum_subprocess():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from repro.distributed.collectives import psum_compressed, shard_map
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((4,), ("data",))
         x = jnp.arange(32, dtype=jnp.float32).reshape(4, 8) / 7.0
 
         @partial(shard_map, mesh=mesh,
@@ -133,8 +136,9 @@ def test_gpipe_matches_sequential_subprocess():
         """
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.pipeline import gpipe_apply
+        from repro.launch.mesh import auto_mesh
         S, M, mb, d = 4, 6, 2, 8
-        mesh = jax.make_mesh((S,), ("stage",))
+        mesh = auto_mesh((S,), ("stage",))
         key = jax.random.PRNGKey(0)
         w = jax.random.normal(key, (S, d, d)) * 0.3
         b = jax.random.normal(jax.random.fold_in(key, 1), (S, d)) * 0.1
@@ -168,9 +172,10 @@ def test_small_mesh_train_step_executes_subprocess():
         from repro.training.optimizer import AdamWConfig, init_opt_state
         from repro.training.train_step import make_rl_train_step
         from repro.models import model as M
+        from repro.launch.mesh import auto_mesh
 
         cfg = get_arch("qwen2-1.5b").reduced()
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = auto_mesh((4, 2), ("data", "model"))
         params = M.init_params(cfg, jax.random.PRNGKey(0))
         opt = init_opt_state(params)
         b, t = 8, 32

@@ -1,13 +1,13 @@
 """Equivalence: the batched PrefillRunner / compacted DecodeRunner must
-reproduce the seed engine's single-row path bit-for-bit.
+reproduce the seed engine's single-row path.
 
 The seed path is still constructible (``batched_prefill=False`` +
 ``compact_decode=False`` forces single-row prefill groups and full
 ``max_slots`` decode), so every test runs the same workload through both
-configurations and compares tokens and behavior logprobs exactly — greedy
-decoding makes token selection key-independent, and on the XLA CPU/TPU
-backends batched matmul rows are bitwise independent, so equality is exact,
-not approximate."""
+configurations and compares tokens exactly and behavior logprobs within
+``LOGPROB_ATOL`` — greedy decoding makes token selection key-independent,
+and batched matmul rows agree with single rows to float32 rounding (XLA
+may group a row's sums differently for a different batch shape)."""
 import jax
 import numpy as np
 import pytest
@@ -20,6 +20,12 @@ from repro.rollout import runners
 
 CFG = get_arch("qwen2-1.5b").reduced()
 PARAMS = M.init_params(CFG, jax.random.PRNGKey(0))
+# Tolerance for behavior logprobs of two paths that run the same rows in
+# batches of different shapes (batched vs single-row prefill, compaction
+# buckets, suffix-prefill forks, a busier migration destination): XLA:CPU
+# reassociates float32 sums by batch shape (and thread count), so logprobs
+# drift by ~1 ulp (up to 1.2e-6 seen). Tokens are still compared exactly.
+LOGPROB_ATOL = 1e-5
 
 
 def mk_traj(tid, prompt_len=6, max_new=16):
@@ -47,7 +53,8 @@ def run_workload(inst, trajs, steps=60):
     return done
 
 
-def assert_same_streams(trajs_a, trajs_b):
+def assert_same_streams(trajs_a, trajs_b, *, atol=LOGPROB_ATOL):
+    """Tokens equal exactly; behavior logprobs equal within ``atol``."""
     for ta, tb in zip(trajs_a, trajs_b):
         assert ta.traj_id == tb.traj_id
         assert ta.response == tb.response, (
@@ -55,8 +62,9 @@ def assert_same_streams(trajs_a, trajs_b):
         )
         a = np.asarray(ta.behavior_logprobs)
         b = np.asarray(tb.behavior_logprobs)
-        np.testing.assert_array_equal(
-            a, b, err_msg=f"traj {ta.traj_id} behavior logprobs diverge"
+        np.testing.assert_allclose(
+            a, b, rtol=0, atol=atol,
+            err_msg=f"traj {ta.traj_id} behavior logprobs diverge",
         )
 
 
@@ -553,9 +561,10 @@ def test_group_interrupt_releases_shared_blocks_once(lazy):
 def test_group_straggler_forks_resident_prefix_across_waves(temperature):
     """A member admitted AFTER its siblings (no free slot in their wave)
     forks the still-resident prefix: no duplicate prompt blocks, and the
-    token stream still matches the all-independent path bit-for-bit —
-    greedy AND stochastic (suffix prefill keeps logits bitwise equal, and
-    sampling keys are pure functions of (seed, traj_id, position))."""
+    token stream still matches the all-independent path exactly — greedy
+    AND stochastic (suffix prefill reproduces the logits to float32
+    rounding, and sampling keys are pure functions of (seed, traj_id,
+    position)); behavior logprobs agree within LOGPROB_ATOL."""
     reset_traj_ids()
     bs, P = 16, 37                       # 2 full shared blocks + tail
     NO_EOS = -1
@@ -597,7 +606,8 @@ def test_straggler_fork_survives_donor_interrupt_mid_decode():
     interrupted mid-decode. The forked blocks are refcounted, so the
     donor's release must not free them out from under the straggler — the
     allocator invariants hold at every step and the straggler's stream
-    still matches the all-independent path bit-for-bit."""
+    still matches the all-independent path (tokens exactly, logprobs
+    within LOGPROB_ATOL)."""
     reset_traj_ids()
     bs, P = 16, 37
     NO_EOS = -1
@@ -717,10 +727,10 @@ def test_paged_admission_wave_uses_live_free_count():
 # ============================================== per-slot PRNG key streams
 # The sampling key for trajectory t's p-th token is
 # fold_in(fold_in(base, t), p) — a pure function of (seed, traj_id,
-# position). Stochastic decode is therefore bit-for-bit invariant under
+# position). Stochastic token streams are therefore invariant under
 # batch composition, slot assignment, instance identity, and migration
-# destination: the properties the ROADMAP's "batched sampling key
-# redesign" item called for.
+# destination; behavior logprobs are equal within LOGPROB_ATOL where the
+# batch shape changes.
 
 
 def test_stochastic_stream_invariant_under_batch_composition():
@@ -746,11 +756,7 @@ def test_stochastic_stream_invariant_under_batch_composition():
 
     alone = run(0)
     crowded = run(3)
-    assert alone.response == crowded.response
-    np.testing.assert_array_equal(
-        np.asarray(alone.behavior_logprobs),
-        np.asarray(crowded.behavior_logprobs),
-    )
+    assert_same_streams([alone], [crowded])
 
 
 def test_stochastic_stream_invariant_under_instance_identity():
@@ -773,7 +779,8 @@ def test_stochastic_stream_invariant_under_instance_identity():
 def test_stochastic_migration_destination_invariant():
     """Interrupt mid-stream, then finish on instance B vs instance C (with
     different occupancy): the continuation resumes the key stream at its
-    position and the final streams match bitwise."""
+    position and the final token streams match (logprobs within
+    LOGPROB_ATOL)."""
     reset_traj_ids()
 
     def run(busy_dest):
