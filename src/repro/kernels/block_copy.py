@@ -75,6 +75,7 @@ def copy_pool_blocks(
         ],
         # operand order: (src, dst, k_pool, v_pool)
         input_output_aliases={2: 0, 3: 1},
+        name="copy_pool_blocks",
         interpret=interpret,
     )(src.astype(jnp.int32), dst.astype(jnp.int32), k_pool, v_pool)
     return new_k, new_v

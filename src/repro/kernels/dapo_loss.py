@@ -82,6 +82,7 @@ def dapo_loss(
         ],
         out_specs=[partial_spec, partial_spec, partial_spec],
         out_shape=[partial_shape, partial_shape, partial_shape],
+        name="dapo_loss",
         interpret=interpret,
     )(logprobs, old_logprobs, adv2d, mask)
 

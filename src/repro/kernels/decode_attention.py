@@ -249,6 +249,7 @@ def decode_attention_update(
             jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
         ],
         input_output_aliases={2: 1, 3: 2},  # k_cache->new_k, v_cache->new_v
+        name="decode_attention_update",
         interpret=interpret,
     )(scalars, q, k_cache, v_cache, k_new, v_new)
     return out, new_k, new_v
@@ -295,6 +296,7 @@ def decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
+        name="decode_attention",
         interpret=interpret,
     )(lengths.astype(jnp.int32), q, k_cache, v_cache)
     return out

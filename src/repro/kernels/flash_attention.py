@@ -156,6 +156,7 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(qh, kh, vh)
     return out.reshape(b, h, sq, hd).transpose(0, 2, 1, 3)

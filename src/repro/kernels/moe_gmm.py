@@ -66,6 +66,7 @@ def grouped_matmul(
         out_specs=pl.BlockSpec((1, bc, bf), lambda ie, ic, if_, id_: (ie, ic, if_)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), jnp.float32),
         scratch_shapes=[],
+        name="grouped_matmul",
         interpret=interpret,
     )(x, w)
     return out

@@ -144,6 +144,7 @@ def paged_decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
+        name="paged_decode_attention",
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q,
       k_pool, v_pool)
@@ -275,6 +276,7 @@ def paged_prefill_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, sq, h, hd), q.dtype),
+        name="paged_prefill_attention",
         interpret=interpret,
     )(block_tables.astype(jnp.int32), meta, q, k_pool, v_pool)
     return out
@@ -434,6 +436,7 @@ def paged_decode_attention_update(
         ],
         # operand order: (tables, meta, q, k_pool, v_pool, k_new, v_new)
         input_output_aliases={3: 1, 4: 2},
+        name="paged_decode_attention_update",
         interpret=interpret,
     )(block_tables.astype(jnp.int32), meta, q, k_pool, v_pool, k_new, v_new)
     return out, new_k, new_v

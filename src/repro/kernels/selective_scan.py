@@ -126,6 +126,7 @@ def selective_scan(
             jax.ShapeDtypeStruct((b, i, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bi, n), jnp.float32)],
+        name="selective_scan",
         interpret=interpret,
     )(dt, x, bmat, cmat, a, h0)
     return y, h_final

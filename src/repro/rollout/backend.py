@@ -176,6 +176,9 @@ class SimBackend:
         self.complete_since_sync: set = set()
         self.decode_tokens = 0.0
         self.prefill_tokens = 0.0
+        # the part of prefill_tokens forwarded for trajectories that had
+        # already generated (RolloutInstance.reprefill_tokens)
+        self.reprefill_tokens = 0.0
         self.preemptions = 0                   # sim pools never preempt
         self.shared_prefix_hits = 0
         self.block_copies = 0                  # mirrored CoW tail copies
@@ -235,6 +238,8 @@ class SimBackend:
                 max(self.stall_until, now) + prefill / self._prefill_tps
             )
             self.prefill_tokens += prefill
+            if traj.length > len(traj.prompt):
+                self.reprefill_tokens += prefill
         if self.on_admit is not None:
             self.on_admit(self.inst_id, [traj.traj_id])
 

@@ -74,6 +74,7 @@ from repro.core.snapshot import InstanceSnapshot
 from repro.core.types import Trajectory, TrajStatus
 from repro.data.tokenizer import EOS
 from repro.models import model as M
+from repro.obs.spans import span
 from repro.rollout.kv_allocator import (
     NULL_BLOCK,
     BlockExhausted,
@@ -218,6 +219,10 @@ class RolloutInstance:
         # telemetry
         self.decode_steps = 0
         self.prefill_tokens = 0
+        # tokens forwarded again at admission for trajectories that had
+        # already generated (re-admission after a Pull's interrupt, or a
+        # preemption), on the prefill_tokens basis
+        self.reprefill_tokens = 0
         self.decode_tokens = 0
         self.preemptions = 0
         self.shared_prefix_hits = 0       # members admitted off a shared prompt
@@ -255,6 +260,23 @@ class RolloutInstance:
                 cfg, max_slots=max_slots, temperature=temperature
             )
         self._overflow_done: List[Trajectory] = []
+        self.tracer = None
+
+    @property
+    def tracer(self):
+        """The ``TrajectoryTracer`` that the program spans
+        (``repro.obs.spans``) of the instance and its runners also record
+        into; None: the profiler's trace only."""
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        self._tracer = tracer
+        for runner in (
+            self.prefill_runner, self.paged_decode_runner, self.decode_runner
+        ):
+            if runner is not None:
+                runner.tracer = tracer
 
     # --------------------------------------------------- runner factories
     def _make_prefill_runner(self, cfg: ArchConfig, **kw) -> PrefillRunner:
@@ -353,12 +375,14 @@ class RolloutInstance:
         return out
 
     def pull(self, params: Any, version: int, now: float = 0.0) -> None:
-        self.params = params
-        self.inst_version = version
-        self.complete_since_sync.clear()
-        # residents were interrupted by the coordinator beforehand (Alg. 1);
-        # anything left is re-prefilled lazily on its next admit
-        self._admit()
+        with span("engine.pull", self._tracer, inst=self.inst_id):
+            self.params = params
+            self.inst_version = version
+            self.complete_since_sync.clear()
+            # residents were interrupted by the coordinator beforehand
+            # (Alg. 1); anything left is re-prefilled lazily on its next
+            # admit
+            self._admit()
 
     # ---------------------------------------------------------------- admit
     def _admission_charge(self, length: int) -> float:
@@ -477,149 +501,158 @@ class RolloutInstance:
         one prompt prefill, full blocks shared (refcounted), private tail
         copies — charging the shared blocks once.
         """
-        free = [i for i, t in enumerate(self.slots) if t is None]
-        jobs: List[PrefillJob] = []
-        trajs: List[Trajectory] = []
-        planned_bytes = self.kv_bytes()
-        while self.waiting and free:
-            run = min(self._share_run(), len(free))
-            if run >= 2:
-                planned = self._admit_group(
-                    run, free, jobs, trajs, planned_bytes
-                )
-                if planned is not None:
-                    planned_bytes = planned
-                    continue
-            nxt = self.waiting[0]
-            tokens = list(nxt.prompt) + list(nxt.response)
-            cache_len = len(tokens) + self._pos_offset
-            # cross-wave prefix join: a straggler group member admitted
-            # after its siblings forks their still-resident prefix blocks
-            # instead of duplicating them. On suffix-capable families only
-            # the tokens past the resident prefix are forwarded; otherwise
-            # the full forward runs with its full-block KV writes discarded
-            # into the null sink. A preempted member re-admitting with a
-            # partial response forks too — the shared prefix covers its
-            # prompt, and the suffix is the prompt tail plus the response.
-            fork_pk = None
-            shared_blocks = 0
-            if (
-                self.paged
-                and self.share_prefix
-                and len(tokens) < self.max_len - 1
-                and nxt.group_id >= 0
-                and not nxt.sim_generated
-            ):
-                h, tp = nxt.prompt_key()
-                fork_pk = self._prefix.find(
-                    nxt.group_id, tp, prompt_hash=h
-                )
-                if fork_pk is not None:
-                    shared_blocks = (
-                        self._prefix.tokens(fork_pk) // self.kv_block_size
-                    )
-                    if shared_blocks == 0:
-                        fork_pk = None  # tail-only registration: no prefix
-            charge = self._admission_charge(self._slot_len(nxt))
-            charge -= self.k5_local * self.kv_block_size * shared_blocks
-            if planned_bytes + max(charge, 0.0) > self.kv_budget:
-                break
-            if self.paged:
-                # ``alloc`` below draws down ``n_free`` as this pass admits,
-                # so the availability check is against the live free count
-                need_blocks = (
-                    blocks_for_tokens(cache_len, self.kv_block_size)
-                    - shared_blocks
-                )
-                if (
-                    len(tokens) < self.max_len - 1
-                    and need_blocks > self.allocator.n_free
-                ):
-                    break  # pool exhausted: wait for releases
-            self.waiting.popleft()
-            slot = free.pop(0)
-            if len(tokens) >= self.max_len - 1:
-                # no room to generate: finish immediately (engine-level cap)
-                nxt.finished = True
-                nxt.status = TrajStatus.GENERATED
-                self.complete_since_sync.add(nxt.traj_id)
-                self._overflow_done.append(nxt)
-                continue
-            sub = self._sample_key(nxt)
-            blocks = None
-            suffix_start: Optional[int] = None
-            resident_tokens = 0
-            if self.paged:
-                if fork_pk is not None:
-                    shared = self.allocator.table(
-                        self._prefix.member_of(fork_pk)
-                    )[:shared_blocks]
-                    own = self.allocator.fork(nxt.traj_id, shared, cache_len)
-                    self._prefix.join(fork_pk, nxt.traj_id)
-                    if self._suffix_ok:
-                        # suffix prefill: forward only the tokens past the
-                        # resident prefix — the real block table is passed
-                        # so attention reads the donor's resident KV.
-                        # Block-aligned forks re-forward one prompt token
-                        # for logits; its redundant K/V write is redirected
-                        # to the null sink inside paged_prefill_step.
-                        resident_tokens = shared_blocks * self.kv_block_size
-                        suffix_start = min(resident_tokens, len(tokens) - 1)
-                        blocks = shared + own
-                        self.prefill_tokens_saved += suffix_start
-                    else:
-                        # scatter target: the shared blocks are already
-                        # written (identical prompt KV) — aim those rows at
-                        # the null garbage block, keep only tail/own writes
-                        blocks = [NULL_BLOCK] * shared_blocks + own
-                    planned_bytes += self.k5_local * self.kv_block_size * len(own)
-                    self.shared_prefix_hits += 1
-                else:
-                    blocks = self.allocator.alloc(nxt.traj_id, cache_len)
-                    planned_bytes += (
-                        self.k5_local * self.kv_block_size * len(blocks)
-                    )
-            else:
-                planned_bytes += self.k5_local * (self._slot_len(nxt) + 1)
-            jobs.append(PrefillJob(
-                slot=slot, tokens=tokens, key=sub, blocks=blocks,
-                suffix_start=suffix_start, resident_tokens=resident_tokens,
-            ))
-            trajs.append(nxt)
-        if not jobs:
+        if not self.waiting:
             return
-        if not self.paged:
-            # the decode runner may hold active rows compacted out of the
-            # batch cache; sync them back before the prefill scatter writes
-            # new rows (the paged pool needs no such coherence step)
-            self.cache = self.decode_runner.flush(self.cache)
-        self.cache, result = self.prefill_runner.run(
-            self.params, self.cache, jobs
-        )
-        self.prefill_tokens += result.prefill_tokens
-        self.block_copies += result.tail_copies
-        member_slots: List[int] = []
-        member_lens: List[int] = []
-        for job in jobs:
-            member_slots.append(job.slot)
-            member_slots.extend(job.extra_slots)
-            member_lens.extend([len(job.tokens)] * job.n_members)
-        last = self._last_tokens
-        for slot, n_tok, traj, tok, blp in zip(
-            member_slots, member_lens, trajs, result.tokens, result.logprobs
+        with span(
+            "engine.admit", self._tracer, inst=self.inst_id,
+            waiting=len(self.waiting),
         ):
-            self._record_token(traj, tok, blp)
-            last = last.at[slot].set(tok)
-            traj.status = TrajStatus.RUNNING
-            self.slots[slot] = traj
-            self._slot_pos[slot] = n_tok + self._pos_offset
-            self._slot_seq[slot] = self._admit_seq
-            self._admit_seq += 1
+            free = [i for i, t in enumerate(self.slots) if t is None]
+            jobs: List[PrefillJob] = []
+            trajs: List[Trajectory] = []
+            planned_bytes = self.kv_bytes()
+            while self.waiting and free:
+                run = min(self._share_run(), len(free))
+                if run >= 2:
+                    planned = self._admit_group(
+                        run, free, jobs, trajs, planned_bytes
+                    )
+                    if planned is not None:
+                        planned_bytes = planned
+                        continue
+                nxt = self.waiting[0]
+                tokens = list(nxt.prompt) + list(nxt.response)
+                cache_len = len(tokens) + self._pos_offset
+                # cross-wave prefix join: a straggler group member admitted
+                # after its siblings forks their still-resident prefix blocks
+                # instead of duplicating them. On suffix-capable families only
+                # the tokens past the resident prefix are forwarded; otherwise
+                # the full forward runs with its full-block KV writes discarded
+                # into the null sink. A preempted member re-admitting with a
+                # partial response forks too — the shared prefix covers its
+                # prompt, and the suffix is the prompt tail plus the response.
+                fork_pk = None
+                shared_blocks = 0
+                if (
+                    self.paged
+                    and self.share_prefix
+                    and len(tokens) < self.max_len - 1
+                    and nxt.group_id >= 0
+                    and not nxt.sim_generated
+                ):
+                    h, tp = nxt.prompt_key()
+                    fork_pk = self._prefix.find(
+                        nxt.group_id, tp, prompt_hash=h
+                    )
+                    if fork_pk is not None:
+                        shared_blocks = (
+                            self._prefix.tokens(fork_pk) // self.kv_block_size
+                        )
+                        if shared_blocks == 0:
+                            fork_pk = None  # tail-only registration: no prefix
+                charge = self._admission_charge(self._slot_len(nxt))
+                charge -= self.k5_local * self.kv_block_size * shared_blocks
+                if planned_bytes + max(charge, 0.0) > self.kv_budget:
+                    break
+                if self.paged:
+                    # ``alloc`` below draws down ``n_free`` as this pass admits,
+                    # so the availability check is against the live free count
+                    need_blocks = (
+                        blocks_for_tokens(cache_len, self.kv_block_size)
+                        - shared_blocks
+                    )
+                    if (
+                        len(tokens) < self.max_len - 1
+                        and need_blocks > self.allocator.n_free
+                    ):
+                        break  # pool exhausted: wait for releases
+                self.waiting.popleft()
+                slot = free.pop(0)
+                if len(tokens) >= self.max_len - 1:
+                    # no room to generate: finish immediately (engine-level cap)
+                    nxt.finished = True
+                    nxt.status = TrajStatus.GENERATED
+                    self.complete_since_sync.add(nxt.traj_id)
+                    self._overflow_done.append(nxt)
+                    continue
+                sub = self._sample_key(nxt)
+                blocks = None
+                suffix_start: Optional[int] = None
+                resident_tokens = 0
+                if self.paged:
+                    if fork_pk is not None:
+                        shared = self.allocator.table(
+                            self._prefix.member_of(fork_pk)
+                        )[:shared_blocks]
+                        own = self.allocator.fork(nxt.traj_id, shared, cache_len)
+                        self._prefix.join(fork_pk, nxt.traj_id)
+                        if self._suffix_ok:
+                            # suffix prefill: forward only the tokens past the
+                            # resident prefix — the real block table is passed
+                            # so attention reads the donor's resident KV.
+                            # Block-aligned forks re-forward one prompt token
+                            # for logits; its redundant K/V write is redirected
+                            # to the null sink inside paged_prefill_step.
+                            resident_tokens = shared_blocks * self.kv_block_size
+                            suffix_start = min(resident_tokens, len(tokens) - 1)
+                            blocks = shared + own
+                            self.prefill_tokens_saved += suffix_start
+                        else:
+                            # scatter target: the shared blocks are already
+                            # written (identical prompt KV) — aim those rows at
+                            # the null garbage block, keep only tail/own writes
+                            blocks = [NULL_BLOCK] * shared_blocks + own
+                        planned_bytes += self.k5_local * self.kv_block_size * len(own)
+                        self.shared_prefix_hits += 1
+                    else:
+                        blocks = self.allocator.alloc(nxt.traj_id, cache_len)
+                        planned_bytes += (
+                            self.k5_local * self.kv_block_size * len(blocks)
+                        )
+                else:
+                    planned_bytes += self.k5_local * (self._slot_len(nxt) + 1)
+                jobs.append(PrefillJob(
+                    slot=slot, tokens=tokens, key=sub, blocks=blocks,
+                    suffix_start=suffix_start, resident_tokens=resident_tokens,
+                ))
+                trajs.append(nxt)
+            if not jobs:
+                return
             if not self.paged:
-                self._kv_bytes += self.k5 * self._slot_len(traj)
-        self._last_tokens = last
-        if self.on_admit is not None:
-            self.on_admit(self.inst_id, [t.traj_id for t in trajs])
+                # the decode runner may hold active rows compacted out of the
+                # batch cache; sync them back before the prefill scatter writes
+                # new rows (the paged pool needs no such coherence step)
+                self.cache = self.decode_runner.flush(self.cache)
+            self.cache, result = self.prefill_runner.run(
+                self.params, self.cache, jobs
+            )
+            self.prefill_tokens += result.prefill_tokens
+            self.block_copies += result.tail_copies
+            member_slots: List[int] = []
+            member_lens: List[int] = []
+            for job in jobs:
+                if trajs[len(member_slots)].n_generated:
+                    # prompt and partial response forwarded again
+                    self.reprefill_tokens += job.forwarded
+                member_slots.append(job.slot)
+                member_slots.extend(job.extra_slots)
+                member_lens.extend([len(job.tokens)] * job.n_members)
+            last = self._last_tokens
+            for slot, n_tok, traj, tok, blp in zip(
+                member_slots, member_lens, trajs, result.tokens, result.logprobs
+            ):
+                self._record_token(traj, tok, blp)
+                last = last.at[slot].set(tok)
+                traj.status = TrajStatus.RUNNING
+                self.slots[slot] = traj
+                self._slot_pos[slot] = n_tok + self._pos_offset
+                self._slot_seq[slot] = self._admit_seq
+                self._admit_seq += 1
+                if not self.paged:
+                    self._kv_bytes += self.k5 * self._slot_len(traj)
+            self._last_tokens = last
+            if self.on_admit is not None:
+                self.on_admit(self.inst_id, [t.traj_id for t in trajs])
 
     # ----------------------------------------------------------------- step
     def _sample_key(self, traj: Trajectory) -> jax.Array:
@@ -706,63 +739,71 @@ class RolloutInstance:
     def step(self, now: float = 0.0, dt: float = 0.0) -> List[Trajectory]:
         """One batched decode step over the active slots. Returns completed
         trajectories (removed from their slots)."""
-        done: List[Trajectory] = []
-        if self._overflow_done:
-            done.extend(self._overflow_done)
-            self._overflow_done.clear()
-        if self.paged:
-            self._ensure_decode_blocks()
-        active = [i for i, t in enumerate(self.slots) if t is not None]
-        if not active:
-            return done
-        keys = stream_keys(
-            self._base_key,
-            jnp.asarray(
-                [self.slots[s].traj_id for s in active], jnp.uint32
-            ),
-            jnp.asarray(
-                [self.slots[s].n_generated for s in active], jnp.uint32
-            ),
-        )
-        if self.paged:
-            tables = {
-                s: self.allocator.table(self.slots[s].traj_id) for s in active
-            }
-            self.cache, self._last_tokens, result = (
-                self.paged_decode_runner.run(
-                    self.params, self.cache, active, tables,
-                    self._last_tokens, keys,
+        with span("engine.step", self._tracer, inst=self.inst_id):
+            done: List[Trajectory] = []
+            if self._overflow_done:
+                done.extend(self._overflow_done)
+                self._overflow_done.clear()
+            if self.paged:
+                with span("engine.blocks", self._tracer, inst=self.inst_id):
+                    self._ensure_decode_blocks()
+            active = [i for i, t in enumerate(self.slots) if t is not None]
+            if not active:
+                return done
+            keys = stream_keys(
+                self._base_key,
+                jnp.asarray(
+                    [self.slots[s].traj_id for s in active], jnp.uint32
+                ),
+                jnp.asarray(
+                    [self.slots[s].n_generated for s in active], jnp.uint32
+                ),
+            )
+            if self.paged:
+                tables = {
+                    s: self.allocator.table(self.slots[s].traj_id)
+                    for s in active
+                }
+                self.cache, self._last_tokens, result = (
+                    self.paged_decode_runner.run(
+                        self.params, self.cache, active, tables,
+                        self._last_tokens, keys,
+                    )
                 )
-            )
-        else:
-            self.cache, self._last_tokens, result = self.decode_runner.run(
-                self.params,
-                self.cache,
-                active,
-                self._last_tokens,
-                keys,
-                compact=self.compact_decode,
-            )
-        self.decode_steps += 1
-        self.decode_tokens += len(active)
+            else:
+                self.cache, self._last_tokens, result = (
+                    self.decode_runner.run(
+                        self.params,
+                        self.cache,
+                        active,
+                        self._last_tokens,
+                        keys,
+                        compact=self.compact_decode,
+                    )
+                )
+            self.decode_steps += 1
+            self.decode_tokens += len(active)
 
-        for slot, token, blp, pos in zip(
-            result.slots, result.tokens, result.logprobs, result.positions
-        ):
-            traj = self.slots[slot]
-            self._record_token(traj, int(token), float(blp))
-            self._slot_pos[slot] = int(pos)
-            if not self.paged:
-                self._kv_bytes += self.k5
-            if traj.finished or int(pos) >= self.max_len - 1:
-                traj.finished = True
-                traj.status = TrajStatus.GENERATED
-                self.complete_since_sync.add(traj.traj_id)
-                done.append(traj)
-                self._release_slot(slot)
-        if done:
-            self._admit()
-        return done
+            with span("engine.record", self._tracer, inst=self.inst_id,
+                      rows=len(active)):
+                for slot, token, blp, pos in zip(
+                    result.slots, result.tokens, result.logprobs,
+                    result.positions,
+                ):
+                    traj = self.slots[slot]
+                    self._record_token(traj, int(token), float(blp))
+                    self._slot_pos[slot] = int(pos)
+                    if not self.paged:
+                        self._kv_bytes += self.k5
+                    if traj.finished or int(pos) >= self.max_len - 1:
+                        traj.finished = True
+                        traj.status = TrajStatus.GENERATED
+                        self.complete_since_sync.add(traj.traj_id)
+                        done.append(traj)
+                        self._release_slot(slot)
+            if done:
+                self._admit()
+            return done
 
     # ------------------------------------------------------------- snapshot
     def snapshot(self) -> InstanceSnapshot:

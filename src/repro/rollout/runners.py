@@ -32,8 +32,8 @@ in ``RolloutInstance`` (``repro.rollout.engine``).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -43,6 +43,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.distributed.ctx import gather_params
 from repro.models import model as M
+from repro.obs.spans import span
 from repro.rollout.sampler import sample_rows
 
 Cache = Dict[str, Any]
@@ -52,6 +53,19 @@ BATCH_AXIS = {
     "pos": 0, "k": 1, "v": 1, "conv": 1, "ssm": 1, "xk": 1, "xv": 1,
     "mlstm": 2, "slstm": 1,
 }
+
+
+def named_jit(name: str, fn: Callable, **jit_kw) -> Callable:
+    """``jax.jit(fn, **jit_kw)`` under a fixed name: the program lowers as
+    ``jit_<name>``, the name its module carries in a device trace (and
+    that the benchmark's trace readers match), however ``fn`` was built."""
+
+    @functools.wraps(fn)
+    def program(*a, **kw):
+        return fn(*a, **kw)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **jit_kw)
 
 
 def round_up(n: int, mult: int) -> int:
@@ -150,6 +164,12 @@ class PrefillJob:
         return len(self.tokens)
 
     @property
+    def forwarded(self) -> int:
+        """Tokens this job runs through the model (its suffix in suffix
+        mode): what it adds to ``PrefillResult.prefill_tokens``."""
+        return len(self.tokens) - (self.suffix_start or 0)
+
+    @property
     def n_members(self) -> int:
         return 1 + len(self.extra_slots)
 
@@ -209,26 +229,34 @@ class PrefillRunner:
         # shard-stored params are gathered replicated inside the step
         # (ctx.gather_params: ZeRO-3-style JIT materialization, no-op on
         # single-device instances) so matmul widths never change
-        self._jit_prefill = jax.jit(
+        self._jit_prefill = named_jit(
+            "rollout_prefill",
             lambda params, *a, **kw: M.prefill(
                 cfg, gather_params(params), *a, impl=impl, **kw
-            )
+            ),
         )
-        self._jit_scatter = jax.jit(scatter_rows)
-        self._jit_paged_scatter = jax.jit(self._paged_scatter)
+        self._jit_scatter = named_jit("rollout_scatter", scatter_rows)
+        self._jit_paged_scatter = named_jit(
+            "rollout_paged_scatter", self._paged_scatter
+        )
         # donate the cache: the copy is always fed a fresh intermediate (a
         # scatter output) and donating lets the inner Pallas aliasing move
         # only the touched blocks instead of round-tripping the whole pool
-        self._jit_block_copy = jax.jit(
-            M.copy_kv_blocks, static_argnames=("impl",), donate_argnums=(0,)
+        self._jit_block_copy = named_jit(
+            "rollout_block_copy", M.copy_kv_blocks,
+            static_argnames=("impl",), donate_argnums=(0,),
         )
         # per-row sampling with per-trajectory stream keys: each member's
         # first token is a function of (its key, its logits row) only
-        self._jit_sample = jax.jit(
-            lambda lg, ks: sample_rows(lg, ks, temperature=self.temperature)
+        self._jit_sample = named_jit(
+            "rollout_prefill_sample",
+            lambda lg, ks: sample_rows(lg, ks, temperature=self.temperature),
         )
         # suffix-prefill dispatches, one per (suffix bucket, batch rows)
         self._suffix_steps: Dict[Tuple[int, int], Any] = {}
+        # program spans also record here (a TrajectoryTracer; None: the
+        # profiler's trace only)
+        self.tracer = None
 
     def bucket_of(self, n_tokens: int) -> int:
         return min(round_up(max(n_tokens, 1), self.prefill_bucket), self.max_len)
@@ -312,7 +340,7 @@ class PrefillRunner:
                     )
                 return logits, out
 
-            fn = jax.jit(step)
+            fn = named_jit("rollout_suffix_prefill", step)
             self._suffix_steps[(bucket, n)] = fn
         return fn
 
@@ -344,33 +372,35 @@ class PrefillRunner:
         for b, nb in order:
             group = by_bucket[(b, nb)]
             n = len(group)
-            rows = np.zeros((n, b), np.int32)
-            q_off = np.zeros((n,), np.int32)
-            res = np.zeros((n,), np.int32)
-            lens = np.zeros((n,), np.int32)
-            tables = np.full((n, nb), self.paged_null_block, np.int32)
-            for r, job in enumerate(group):
-                sfx = job.tokens[job.suffix_start:]
-                rows[r, : len(sfx)] = sfx
-                q_off[r] = job.suffix_start
-                res[r] = job.resident_tokens
-                lens[r] = len(job.tokens)
-                tables[r, : len(job.blocks)] = job.blocks
-            slots = jnp.asarray([job.slot for job in group], jnp.int32)
-            logits, cache = self._suffix_step(b, n)(
-                params, cache, jnp.asarray(rows), slots,
-                jnp.asarray(tables), jnp.asarray(q_off),
-                jnp.asarray(res), jnp.asarray(lens),
-            )
-            keys = jnp.stack([job.key for job in group])
-            toks, blps = self._jit_sample(logits, keys)
-            toks_np = np.asarray(toks)
-            blps_np = np.asarray(blps)
+            with span("prefill.dispatch", self.tracer, rows=n):
+                rows = np.zeros((n, b), np.int32)
+                q_off = np.zeros((n,), np.int32)
+                res = np.zeros((n,), np.int32)
+                lens = np.zeros((n,), np.int32)
+                tables = np.full((n, nb), self.paged_null_block, np.int32)
+                for r, job in enumerate(group):
+                    sfx = job.tokens[job.suffix_start:]
+                    rows[r, : len(sfx)] = sfx
+                    q_off[r] = job.suffix_start
+                    res[r] = job.resident_tokens
+                    lens[r] = len(job.tokens)
+                    tables[r, : len(job.blocks)] = job.blocks
+                slots = jnp.asarray([job.slot for job in group], jnp.int32)
+                logits, cache = self._suffix_step(b, n)(
+                    params, cache, jnp.asarray(rows), slots,
+                    jnp.asarray(tables), jnp.asarray(q_off),
+                    jnp.asarray(res), jnp.asarray(lens),
+                )
+                keys = jnp.stack([job.key for job in group])
+                toks, blps = self._jit_sample(logits, keys)
+            with span("prefill.sync", self.tracer, rows=n):
+                toks_np = np.asarray(toks)
+                blps_np = np.asarray(blps)
             for r, job in enumerate(group):
                 base = offsets[id(job)]
                 result.tokens[base] = int(toks_np[r])
                 result.logprobs[base] = float(blps_np[r])
-                result.prefill_tokens += len(job.tokens) - job.suffix_start
+                result.prefill_tokens += job.forwarded
         return cache
 
     def _groups(self, jobs: Sequence[PrefillJob]) -> List[List[PrefillJob]]:
@@ -417,60 +447,62 @@ class PrefillRunner:
         jobs = [j for j in jobs if j.suffix_start is None]
         copies: List[Tuple[int, int]] = []
         for group in self._groups(jobs):
-            bucket = self.bucket_of(max(len(j.tokens) for j in group))
-            rows = np.zeros((len(group), bucket), np.int32)
-            lengths = np.zeros((len(group),), np.int32)
-            for r, job in enumerate(group):
-                rows[r, : len(job.tokens)] = job.tokens
-                lengths[r] = len(job.tokens)
-            fe = (
-                self.frontend_fn(len(group))
-                if self.frontend_fn is not None
-                else None
-            )
-            row_cache = M.init_cache(self.cfg, len(group), self.max_len)
-            logits, row_cache = self._jit_prefill(
-                params,
-                jnp.asarray(rows),
-                jnp.asarray(lengths),
-                row_cache,
-                frontend_embeds=fe,
-            )
-            # member expansion: group jobs scatter one row's small state to
-            # every member slot and sample per member off the shared row
-            member_rows: List[int] = []
-            member_slots: List[int] = []
-            member_keys: List[jax.Array] = []
-            for r, job in enumerate(group):
-                if job.extra_slots and not self.paged_block_size:
-                    raise ValueError("group prefill requires the paged cache")
-                member_rows.extend([r] * job.n_members)
-                member_slots.append(job.slot)
-                member_slots.extend(job.extra_slots)
-                member_keys.append(job.key)
-                member_keys.extend(job.extra_keys)
-                if job.tail_src is not None:
-                    copies.extend((job.tail_src, d) for d in job.tail_dsts)
-            expanded = len(member_rows) != len(group)
-            slots = jnp.asarray(member_slots, jnp.int32)
-            if self.paged_block_size:
-                nb = self.max_len // self.paged_block_size
-                flat = np.full((len(group) * nb,), self.paged_null_block,
-                               np.int32)
+            with span("prefill.dispatch", self.tracer, rows=len(group)):
+                bucket = self.bucket_of(max(len(j.tokens) for j in group))
+                rows = np.zeros((len(group), bucket), np.int32)
+                lengths = np.zeros((len(group),), np.int32)
                 for r, job in enumerate(group):
-                    flat[r * nb : r * nb + len(job.blocks)] = job.blocks
-                cache = self._jit_paged_scatter(
-                    cache, row_cache, slots,
-                    jnp.asarray(member_rows, jnp.int32), jnp.asarray(flat),
+                    rows[r, : len(job.tokens)] = job.tokens
+                    lengths[r] = len(job.tokens)
+                fe = (
+                    self.frontend_fn(len(group))
+                    if self.frontend_fn is not None
+                    else None
                 )
-            else:
-                cache = self._jit_scatter(cache, row_cache, slots)
-            if expanded:
-                logits = logits[jnp.asarray(member_rows, jnp.int32)]
-            keys = jnp.stack(member_keys)
-            toks, blps = self._jit_sample(logits, keys)
-            toks_np = np.asarray(toks)
-            blps_np = np.asarray(blps)
+                row_cache = M.init_cache(self.cfg, len(group), self.max_len)
+                logits, row_cache = self._jit_prefill(
+                    params,
+                    jnp.asarray(rows),
+                    jnp.asarray(lengths),
+                    row_cache,
+                    frontend_embeds=fe,
+                )
+                # member expansion: group jobs scatter one row's small state to
+                # every member slot and sample per member off the shared row
+                member_rows: List[int] = []
+                member_slots: List[int] = []
+                member_keys: List[jax.Array] = []
+                for r, job in enumerate(group):
+                    if job.extra_slots and not self.paged_block_size:
+                        raise ValueError("group prefill requires the paged cache")
+                    member_rows.extend([r] * job.n_members)
+                    member_slots.append(job.slot)
+                    member_slots.extend(job.extra_slots)
+                    member_keys.append(job.key)
+                    member_keys.extend(job.extra_keys)
+                    if job.tail_src is not None:
+                        copies.extend((job.tail_src, d) for d in job.tail_dsts)
+                expanded = len(member_rows) != len(group)
+                slots = jnp.asarray(member_slots, jnp.int32)
+                if self.paged_block_size:
+                    nb = self.max_len // self.paged_block_size
+                    flat = np.full((len(group) * nb,), self.paged_null_block,
+                                   np.int32)
+                    for r, job in enumerate(group):
+                        flat[r * nb : r * nb + len(job.blocks)] = job.blocks
+                    cache = self._jit_paged_scatter(
+                        cache, row_cache, slots,
+                        jnp.asarray(member_rows, jnp.int32), jnp.asarray(flat),
+                    )
+                else:
+                    cache = self._jit_scatter(cache, row_cache, slots)
+                if expanded:
+                    logits = logits[jnp.asarray(member_rows, jnp.int32)]
+                keys = jnp.stack(member_keys)
+                toks, blps = self._jit_sample(logits, keys)
+            with span("prefill.sync", self.tracer, rows=len(group)):
+                toks_np = np.asarray(toks)
+                blps_np = np.asarray(blps)
             m = 0
             for job in group:
                 base = offsets[id(job)]
@@ -478,7 +510,7 @@ class PrefillRunner:
                     result.tokens[base + i] = int(toks_np[m])
                     result.logprobs[base + i] = float(blps_np[m])
                     m += 1
-                result.prefill_tokens += len(job.tokens)
+                result.prefill_tokens += job.forwarded
         if copies:
             # eager CoW: duplicate prefilled tail blocks into each member's
             # private block
@@ -523,10 +555,13 @@ class DecodeRunner:
         self.cfg = cfg
         self.max_slots = max_slots
         self.temperature = temperature
-        self._jit_decode = jax.jit(partial(M.decode_step, cfg))
-        self._jit_gather = jax.jit(gather_rows)
-        self._jit_sample = jax.jit(
-            lambda lg, ks: sample_rows(lg, ks, temperature=self.temperature)
+        self._jit_decode = named_jit(
+            "rollout_decode", functools.partial(M.decode_step, cfg)
+        )
+        self._jit_gather = named_jit("rollout_gather", gather_rows)
+        self._jit_sample = named_jit(
+            "rollout_sample",
+            lambda lg, ks: sample_rows(lg, ks, temperature=self.temperature),
         )
         # fused row-gather + decode per (bucket, n_active): one dispatch
         # per steady-state step
@@ -537,6 +572,8 @@ class DecodeRunner:
         self._rows_arr: Optional[jax.Array] = None   # padded device copy
         self._live_arr: Optional[jax.Array] = None
         self._compact: Optional[Cache] = None
+        # program spans also record here (see PrefillRunner)
+        self.tracer = None
 
     def bucket_of(self, n_active: int) -> int:
         return min(next_pow2(max(n_active, 1)), self.max_slots)
@@ -564,7 +601,7 @@ class DecodeRunner:
                 }
                 return scatter_rows(cache, live_rows, live)
 
-            fn = jax.jit(_flush)
+            fn = named_jit("rollout_flush", _flush)
             self._flushes[(bucket, n)] = fn
         cache = fn(cache, self._compact, self._live_arr)
         self._rows = self._rows_arr = self._live_arr = None
@@ -581,7 +618,7 @@ class DecodeRunner:
                 )
                 return logits, new_compact, new_compact["pos"][:n]
 
-            fn = jax.jit(step)
+            fn = named_jit("rollout_decode", step)
             self._compact_steps[key] = fn
         return fn
 
@@ -611,45 +648,51 @@ class DecodeRunner:
             cache = self.flush(cache)
             return self._run_full(params, cache, active, last_tokens, keys)
 
-        rows_key = tuple(active)
-        if self._rows != rows_key:
-            # occupancy changed: sync the old compact state back, gather the
-            # new active rows (padded with duplicates of the first row; the
-            # pads decode too but are never written back)
-            cache = self.flush(cache)
-            self._rows_arr = jnp.asarray(
-                active + [active[0]] * (bucket - n), jnp.int32
+        with span("decode.dispatch", self.tracer, rows=n):
+            rows_key = tuple(active)
+            if self._rows != rows_key:
+                # occupancy changed: sync the old compact state back,
+                # gather the new active rows (padded with duplicates of the
+                # first row; the pads decode too but are never written back)
+                cache = self.flush(cache)
+                self._rows_arr = jnp.asarray(
+                    active + [active[0]] * (bucket - n), jnp.int32
+                )
+                self._live_arr = jnp.asarray(active, jnp.int32)
+                self._compact = self._jit_gather(cache, self._rows_arr)
+                self._rows = rows_key
+            logits, self._compact, pos_live = self._compact_step(bucket, n)(
+                params, last_tokens, self._compact, self._rows_arr
             )
-            self._live_arr = jnp.asarray(active, jnp.int32)
-            self._compact = self._jit_gather(cache, self._rows_arr)
-            self._rows = rows_key
-        logits, self._compact, pos_live = self._compact_step(bucket, n)(
-            params, last_tokens, self._compact, self._rows_arr
-        )
-        keys_pad = pad_keys(keys, bucket)
-        tokens, blps = self._jit_sample(logits, keys_pad)
-        last_tokens = last_tokens.at[self._live_arr].set(tokens[:n])
-        return cache, last_tokens, DecodeResult(
-            slots=active,
-            tokens=np.asarray(tokens[:n]),
-            logprobs=np.asarray(blps[:n]),
-            positions=np.asarray(pos_live),
-        )
+            keys_pad = pad_keys(keys, bucket)
+            tokens, blps = self._jit_sample(logits, keys_pad)
+            tokens, blps = tokens[:n], blps[:n]
+            last_tokens = last_tokens.at[self._live_arr].set(tokens)
+        with span("decode.sync", self.tracer, rows=n):
+            result = DecodeResult(
+                slots=active,
+                tokens=np.asarray(tokens),
+                logprobs=np.asarray(blps),
+                positions=np.asarray(pos_live),
+            )
+        return cache, last_tokens, result
 
     def _run_full(self, params, cache, active, last_tokens, keys):
         """Seed path: decode all ``max_slots`` rows, mask inactive ones."""
-        prev_pos = cache["pos"]
-        logits, new_cache = self._jit_decode(params, last_tokens, cache)
-        mask = np.zeros((self.max_slots,), bool)
-        mask[active] = True
-        mask_j = jnp.asarray(mask)
-        new_cache["pos"] = jnp.where(mask_j, new_cache["pos"], prev_pos)
-        keys_full = scatter_keys(keys, active, self.max_slots)
-        tokens, blps = self._jit_sample(logits, keys_full)
-        last_tokens = jnp.where(mask_j, tokens, last_tokens)
-        tokens_np = np.asarray(tokens)
-        blps_np = np.asarray(blps)
-        pos_np = np.asarray(new_cache["pos"])
+        with span("decode.dispatch", self.tracer, rows=len(active)):
+            prev_pos = cache["pos"]
+            logits, new_cache = self._jit_decode(params, last_tokens, cache)
+            mask = np.zeros((self.max_slots,), bool)
+            mask[active] = True
+            mask_j = jnp.asarray(mask)
+            new_cache["pos"] = jnp.where(mask_j, new_cache["pos"], prev_pos)
+            keys_full = scatter_keys(keys, active, self.max_slots)
+            tokens, blps = self._jit_sample(logits, keys_full)
+            last_tokens = jnp.where(mask_j, tokens, last_tokens)
+        with span("decode.sync", self.tracer, rows=len(active)):
+            tokens_np = np.asarray(tokens)
+            blps_np = np.asarray(blps)
+            pos_np = np.asarray(new_cache["pos"])
         return new_cache, last_tokens, DecodeResult(
             slots=list(active),
             tokens=tokens_np[active],
@@ -694,9 +737,12 @@ class PagedDecodeRunner:
         self.impl = impl
         self.pool_sharding = pool_sharding
         self._steps: Dict[Tuple[int, int], Any] = {}
-        self._jit_sample = jax.jit(
-            lambda lg, ks: sample_rows(lg, ks, temperature=self.temperature)
+        self._jit_sample = named_jit(
+            "rollout_sample",
+            lambda lg, ks: sample_rows(lg, ks, temperature=self.temperature),
         )
+        # program spans also record here (see PrefillRunner)
+        self.tracer = None
 
     def bucket_of(self, n_active: int) -> int:
         return min(next_pow2(max(n_active, 1)), self.max_slots)
@@ -734,7 +780,7 @@ class PagedDecodeRunner:
                     )
                 return logits, out, new["pos"][:n]
 
-            fn = jax.jit(step)
+            fn = named_jit("rollout_decode", step)
             self._steps[(bucket, n)] = fn
         return fn
 
@@ -751,22 +797,26 @@ class PagedDecodeRunner:
         (cache, last_tokens, result)."""
         active = list(active)
         n = len(active)
-        bucket = self.bucket_of(n)
-        rows = active + [active[0]] * (bucket - n)
-        tables = np.full((bucket, self.nb), self.null_block, np.int32)
-        for r, slot in enumerate(active):
-            bt = block_tables[slot]
-            tables[r, : len(bt)] = bt
-        live = jnp.asarray(active, jnp.int32)
-        logits, cache, pos_live = self._step(bucket, n)(
-            params, last_tokens, cache,
-            jnp.asarray(rows, jnp.int32), live, jnp.asarray(tables),
-        )
-        tokens, blps = self._jit_sample(logits, pad_keys(keys, bucket))
-        last_tokens = last_tokens.at[live].set(tokens[:n])
-        return cache, last_tokens, DecodeResult(
-            slots=active,
-            tokens=np.asarray(tokens[:n]),
-            logprobs=np.asarray(blps[:n]),
-            positions=np.asarray(pos_live),
-        )
+        with span("decode.dispatch", self.tracer, rows=n):
+            bucket = self.bucket_of(n)
+            rows = active + [active[0]] * (bucket - n)
+            tables = np.full((bucket, self.nb), self.null_block, np.int32)
+            for r, slot in enumerate(active):
+                bt = block_tables[slot]
+                tables[r, : len(bt)] = bt
+            live = jnp.asarray(active, jnp.int32)
+            logits, cache, pos_live = self._step(bucket, n)(
+                params, last_tokens, cache,
+                jnp.asarray(rows, jnp.int32), live, jnp.asarray(tables),
+            )
+            tokens, blps = self._jit_sample(logits, pad_keys(keys, bucket))
+            tokens, blps = tokens[:n], blps[:n]
+            last_tokens = last_tokens.at[live].set(tokens)
+        with span("decode.sync", self.tracer, rows=n):
+            result = DecodeResult(
+                slots=active,
+                tokens=np.asarray(tokens),
+                logprobs=np.asarray(blps),
+                positions=np.asarray(pos_live),
+            )
+        return cache, last_tokens, result

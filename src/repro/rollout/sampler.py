@@ -76,20 +76,17 @@ def stream_key(
     return _fold2(base_key, jnp.uint32(traj_id), jnp.uint32(position))
 
 
+@jax.jit
 def stream_keys(
     base_key: jax.Array,
     traj_ids: jax.Array,        # (B,)
     positions: jax.Array,       # (B,)
 ) -> jax.Array:
-    """Batched ``stream_key``: (B, 2) keys, one per (trajectory, position)."""
-    return _fold2_v(base_key, traj_ids, positions)
+    """Batched ``stream_key``: (B, 2) keys, one per (trajectory, position).
+    One program, ``jit_stream_keys`` in a device trace."""
+    return jax.vmap(lambda i, p: _fold2(base_key, i, p))(traj_ids, positions)
 
 
 @jax.jit
 def _fold2(base_key, traj_id, position):
     return jax.random.fold_in(jax.random.fold_in(base_key, traj_id), position)
-
-
-@jax.jit
-def _fold2_v(base_key, traj_ids, positions):
-    return jax.vmap(lambda i, p: _fold2(base_key, i, p))(traj_ids, positions)

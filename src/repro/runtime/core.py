@@ -63,6 +63,7 @@ from repro.core.lifecycle import LifecycleEvent, LifecycleEventKind
 from repro.core.snapshot import collect as collect_snapshots
 from repro.data.tasks import ArithmeticDataset
 from repro.models import model as M
+from repro.obs.spans import span
 from repro.reward.verifier import RewardModel
 from repro.rl.advantages import group_advantages
 from repro.rollout.backend import EngineBackend, create_backend, execute_commands
@@ -337,7 +338,7 @@ class RuntimeCore:
         # telemetry for the time-breakdown benchmark; decode/reward are
         # updated from N instance threads, so those adds take a lock
         self.timers: Dict[str, float] = {
-            "decode": 0.0, "prefill": 0.0, "reward": 0.0, "train": 0.0,
+            "decode": 0.0, "reward": 0.0, "train": 0.0,
             "coordinator": 0.0, "pull": 0.0, "route": 0.0, "interrupt": 0.0,
         }
         self._timers_lock = make_lock("timers")
@@ -424,10 +425,12 @@ class RuntimeCore:
             backend = create_backend("jax", inst_id, **kw)
         if self.tracer is not None:
             # admission/preemption hooks split each span's queue-wait from
-            # its decode segments (set on the inner engine: LockedBackend
+            # its decode segments, and the engine's program spans record
+            # into the tracer too (set on the inner engine: LockedBackend
             # only forwards attribute *reads*)
             backend.on_admit = self.tracer.on_admit
             backend.on_preempt = self.tracer.on_preempt
+            backend.tracer = self.tracer
         return LockedBackend(backend)
 
     def _snapshots(self):
@@ -465,13 +468,12 @@ class RuntimeCore:
             return 0
         t0 = time.perf_counter()
         done = []
-        for _ in range(n_steps):
-            done.extend(handle.step())
+        with span(f"decode[{inst_id}]", self.tracer, steps=n_steps):
+            for _ in range(n_steps):
+                done.extend(handle.step())
         t1 = time.perf_counter()
         with self._timers_lock:
             self.timers["decode"] += t1 - t0
-        if self.tracer is not None:
-            self.tracer.activity(f"decode[{inst_id}]", t0, t1)
         if handle.n_active() > 0:
             # resident KV grew: migration/routing inputs changed even
             # without a completion, so the next cycle must run
@@ -537,8 +539,7 @@ class RuntimeCore:
             and self.ps.version == self._coord_last_ps_version
         ):
             return 0
-        t_cycle = time.perf_counter()
-        with self.coordinator.lock:
+        with span("cycle", self.tracer), self.coordinator.lock:
             # reset *before* snapshotting: events landing mid-cycle re-mark
             # the flag, so their effects are observed by the next cycle
             self._coord_dirty = False
@@ -553,25 +554,22 @@ class RuntimeCore:
                         stack.enter_context(handles[i].lock)
                     n = self._cycle_body(handles, ps_version)
             self._coord_last_ps_version = ps_version
-            if self.tracer is not None:
-                self.tracer.activity(
-                    "cycle", t_cycle, time.perf_counter(),
-                    args={"commands": n},
-                )
             return n
 
     def _cycle_body(self, handles: Dict[int, LockedBackend], ps_version: int) -> int:
         t0 = time.perf_counter()
-        snaps = collect_snapshots(handles)
-        commands = self.coordinator.step(snaps, ps_version)
+        with span("coordinator.decide", self.tracer):
+            snaps = collect_snapshots(handles)
+            commands = self.coordinator.step(snaps, ps_version)
         # RPL003 fix: the coordinator thread's add races the instance
         # threads' locked decode/reward adds (and run()'s final read)
         with self._timers_lock:
             self.timers["coordinator"] += time.perf_counter() - t0
-        res = execute_commands(
-            commands, handles, self.ts, self.ps,
-            timers=self.timers, lifecycle=self.lifecycle,
-        )
+        with span("coordinator.execute", self.tracer, commands=len(commands)):
+            res = execute_commands(
+                commands, handles, self.ts, self.ps,
+                timers=self.timers, lifecycle=self.lifecycle,
+            )
         # a Route that found its trajectory already gone (cross-cycle
         # failure races; any concurrent mutation under streaming's relaxed
         # snapshots) must not skew P
@@ -611,22 +609,22 @@ class RuntimeCore:
         t0 = time.perf_counter()
         with self.coordinator.lock:
             with handle.lock:
-                snap = handle.snapshot()
-                commands = self.coordinator.route_instance(snap, self.ps.version)
-                if commands:
-                    res = execute_commands(
-                        commands, {inst_id: handle}, self.ts, self.ps,
-                        lifecycle=self.lifecycle,
+                with span("coordinator.decide", self.tracer):
+                    snap = handle.snapshot()
+                    commands = self.coordinator.route_instance(
+                        snap, self.ps.version
                     )
+                if commands:
+                    with span("stream_admit", self.tracer,
+                              routes=len(commands)):
+                        res = execute_commands(
+                            commands, {inst_id: handle}, self.ts, self.ps,
+                            lifecycle=self.lifecycle,
+                        )
                     for inst, tid in res.skipped_routes:
                         self.coordinator.spec.apply(Abort(inst, (tid,)))
-        t1 = time.perf_counter()
         with self._timers_lock:
-            self.timers["coordinator"] += t1 - t0
-        if self.tracer is not None and commands:
-            self.tracer.activity(
-                "stream_admit", t0, t1, args={"routes": len(commands)}
-            )
+            self.timers["coordinator"] += time.perf_counter() - t0
         return len(commands)
 
     # ----------------------------------------------------------- the trainer
@@ -639,35 +637,39 @@ class RuntimeCore:
         min_fill = self.rcfg.stream_min_fill if self.rcfg.streaming else None
         if not self.manager.ready(min_fill):
             return None
-        batch_ids = self.coordinator.try_consume(min_fill)
-        if batch_ids is None:
-            return None
-        # consume retires trajectories from the TS registry; payloads were
-        # retained by the RetiredPayloadStore at reward time
-        staleness_hist = list(self.manager.consumed_staleness[-1])
-        trajs = self.retired.take(batch_ids)
-        batch = self._batch_from_trajs(trajs)
-        if batch is None:
-            return None
-        with self._train_lock:
-            self.params, self.opt_state, metrics = self.train_step(
-                self.params, self.opt_state, batch
-            )
-        self.model_version += 1
-        self._push_fn(self.params, self.model_version)
-        t1 = time.perf_counter()
+        with span("train_step", self.tracer, step=self.model_version + 1):
+            batch_ids = self.coordinator.try_consume(min_fill)
+            if batch_ids is None:
+                return None
+            # consume retires trajectories from the TS registry; payloads
+            # were retained by the RetiredPayloadStore at reward time
+            staleness_hist = list(self.manager.consumed_staleness[-1])
+            trajs = self.retired.take(batch_ids)
+            with span("trainer.batch", self.tracer, rows=len(trajs)):
+                batch = self._batch_from_trajs(trajs)
+            if batch is None:
+                return None
+            # the span closes (into the tracer) after the trainer lock,
+            # a terminal leaf of the lock order, is released
+            with span("trainer.dispatch", self.tracer), self._train_lock:
+                self.params, self.opt_state, metrics = self.train_step(
+                    self.params, self.opt_state, batch
+                )
+            self.model_version += 1
+            with span("trainer.push", self.tracer):
+                self._push_fn(self.params, self.model_version)
+            # the first host read of the step's results waits for it:
+            # timers["train"] is the step's host-clock time
+            with span("trainer.sync", self.tracer):
+                loss = float(metrics["loss"])
         with self._timers_lock:
-            self.timers["train"] += t1 - t0
+            self.timers["train"] += time.perf_counter() - t0
         for s in staleness_hist:
             self._m_staleness.observe(s)
-        if self.tracer is not None:
-            self.tracer.activity(
-                "train_step", t0, t1, args={"step": self.model_version}
-            )
         rec = StepRecord(
             step=self.model_version,
             mean_reward=float(np.mean(batch["_rewards"])),
-            loss=float(metrics["loss"]),
+            loss=loss,
             mean_is_ratio=float(metrics.get("mean_is_ratio", 1.0)),
             staleness_hist=staleness_hist,
             wall_time=time.perf_counter(),
@@ -745,6 +747,7 @@ class RuntimeCore:
     _ENGINE_COUNTERS = (
         "decode_steps",
         "prefill_tokens",
+        "reprefill_tokens",
         "decode_tokens",
         "preemptions",
         "shared_prefix_hits",
@@ -839,13 +842,11 @@ class RuntimeCore:
             m.counter("lock_witness_cycles").set_total(viol["cycles"])
             if self.tracer is not None:
                 samples = w.order_violations + w.emit_under_lock
-                now = time.perf_counter()
                 for s in samples[self._witness_exported:]:
-                    self.tracer.activity(
-                        "lock_witness_violation", now, now,
-                        args={k: v for k, v in s.items() if k != "stack"}
-                        | {"stack": "".join(s.get("stack", [])[-4:])},
-                    )
+                    args = {k: v for k, v in s.items() if k != "stack"}
+                    args["stack"] = "".join(s.get("stack", [])[-4:])
+                    with span("lock_witness_violation", self.tracer, **args):
+                        pass
                 self._witness_exported = len(samples)
 
     def export_trace(self, path: Optional[str] = None) -> Optional[dict]:

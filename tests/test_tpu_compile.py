@@ -122,6 +122,15 @@ def test_kernel_compiles_for_v5e(one_chip, name, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("name", KERNELS)
+def test_kernel_lowers_under_its_name(one_chip, name):
+    """Each Pallas call passes ``name=``: the device trace names the
+    kernel's operation so, and the benchmark's readers match it there."""
+    fn, specs = _kernel_case(name, jnp.float32)
+    text = jax.jit(fn).lower(*_shapes(one_chip, *specs)).as_text()
+    assert f'kernel_name = "{name}"' in text
+
+
 def test_trainer_step_compiles_for_v5e(one_chip):
     """The trainer's differentiable path: one published-width layer of
     qwen2-1.5b, value_and_grad + AdamW, built with ``TRAINER_IMPL``."""
