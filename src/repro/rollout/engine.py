@@ -68,6 +68,7 @@ from typing import Any, Callable, Deque, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core.snapshot import InstanceSnapshot
@@ -223,6 +224,11 @@ class RolloutInstance:
         # already generated (re-admission after a Pull's interrupt, or a
         # preemption), on the prefill_tokens basis
         self.reprefill_tokens = 0
+        # device calls the paged decode step issues (programs, host ->
+        # device transfers, eager ops, blocking reads): the stream_keys
+        # program, whose host inputs ride in its call, plus the runner's
+        # DEVICE_CALLS
+        self.decode_device_calls = 0
         self.decode_tokens = 0
         self.preemptions = 0
         self.shared_prefix_hits = 0       # members admitted off a shared prompt
@@ -750,13 +756,15 @@ class RolloutInstance:
             active = [i for i, t in enumerate(self.slots) if t is not None]
             if not active:
                 return done
+            # host arrays ride into the program's call: no eager
+            # transfers of their own
             keys = stream_keys(
                 self._base_key,
-                jnp.asarray(
-                    [self.slots[s].traj_id for s in active], jnp.uint32
+                np.asarray(
+                    [self.slots[s].traj_id for s in active], np.uint32
                 ),
-                jnp.asarray(
-                    [self.slots[s].n_generated for s in active], jnp.uint32
+                np.asarray(
+                    [self.slots[s].n_generated for s in active], np.uint32
                 ),
             )
             if self.paged:
@@ -769,6 +777,9 @@ class RolloutInstance:
                         self.params, self.cache, active, tables,
                         self._last_tokens, keys,
                     )
+                )
+                self.decode_device_calls += (
+                    1 + self.paged_decode_runner.DEVICE_CALLS
                 )
             else:
                 self.cache, self._last_tokens, result = (

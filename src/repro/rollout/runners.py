@@ -709,14 +709,24 @@ class PagedDecodeRunner:
     the per-step block-table array *is* the compaction. Active slots are
     still bucketed to ``next_pow2(n_active)`` rows so matmul cost scales
     with occupancy; only the small per-slot entries (``pos``, hybrid
-    conv/ssm, audio cross caches) are gathered/scattered each step, inside
-    the same jitted dispatch. There is no compact state held between steps,
-    hence no ``flush`` coherence protocol either.
+    conv/ssm, audio cross caches) are gathered/scattered each step. There
+    is no compact state held between steps, hence no ``flush`` coherence
+    protocol either.
+
+    A step is one program (``rollout_decode``) per ``(bucket, n_active)``:
+    row gather, decode, key padding, sampling, the live-row slices and the
+    ``last_tokens`` and per-slot scatters all run inside it. Its index
+    inputs travel in one packed host buffer and its per-row outputs come
+    back in one device -> host read (``DEVICE_CALLS``).
 
     Pad rows duplicate the first active slot's token/position but point
     their block tables at the null block, so their writes land in the
     garbage sink and their outputs are sliced away.
     """
+
+    # device calls per ``run``: the packed index transfer, the program and
+    # the read of its (3, n) output
+    DEVICE_CALLS = 3
 
     def __init__(
         self,
@@ -737,10 +747,6 @@ class PagedDecodeRunner:
         self.impl = impl
         self.pool_sharding = pool_sharding
         self._steps: Dict[Tuple[int, int], Any] = {}
-        self._jit_sample = named_jit(
-            "rollout_sample",
-            lambda lg, ks: sample_rows(lg, ks, temperature=self.temperature),
-        )
         # program spans also record here (see PrefillRunner)
         self.tracer = None
 
@@ -750,7 +756,11 @@ class PagedDecodeRunner:
     def _step(self, bucket: int, n: int):
         fn = self._steps.get((bucket, n))
         if fn is None:
-            def step(params, last_tokens, cache, rows, live, tables):
+            def step(params, last_tokens, cache, idx, keys):
+                # idx = rows (bucket) | live (n) | tables (bucket * nb)
+                rows = idx[:bucket]
+                live = idx[bucket:bucket + n]
+                tables = idx[bucket + n:].reshape(bucket, self.nb)
                 small = {
                     nm: v for nm, v in cache.items() if nm not in ("k", "v")
                 }
@@ -778,7 +788,18 @@ class PagedDecodeRunner:
                     out["v"] = jax.lax.with_sharding_constraint(
                         out["v"], self.pool_sharding
                     )
-                return logits, out, new["pos"][:n]
+                tokens, blps = sample_rows(
+                    logits, pad_keys(keys, bucket),
+                    temperature=self.temperature,
+                )
+                tokens = tokens[:n]
+                last_tokens = last_tokens.at[live].set(tokens)
+                rows_out = jnp.stack([
+                    tokens,
+                    jax.lax.bitcast_convert_type(blps[:n], jnp.int32),
+                    new["pos"][:n],
+                ])
+                return out, last_tokens, rows_out
 
             fn = named_jit("rollout_decode", step)
             self._steps[(bucket, n)] = fn
@@ -799,24 +820,24 @@ class PagedDecodeRunner:
         n = len(active)
         with span("decode.dispatch", self.tracer, rows=n):
             bucket = self.bucket_of(n)
-            rows = active + [active[0]] * (bucket - n)
-            tables = np.full((bucket, self.nb), self.null_block, np.int32)
+            idx = np.full(bucket + n + bucket * self.nb, self.null_block,
+                          np.int32)
+            idx[:n] = active
+            idx[n:bucket] = active[0]
+            idx[bucket:bucket + n] = active
+            tables = idx[bucket + n:].reshape(bucket, self.nb)
             for r, slot in enumerate(active):
                 bt = block_tables[slot]
                 tables[r, : len(bt)] = bt
-            live = jnp.asarray(active, jnp.int32)
-            logits, cache, pos_live = self._step(bucket, n)(
-                params, last_tokens, cache,
-                jnp.asarray(rows, jnp.int32), live, jnp.asarray(tables),
+            cache, last_tokens, out = self._step(bucket, n)(
+                params, last_tokens, cache, idx, keys
             )
-            tokens, blps = self._jit_sample(logits, pad_keys(keys, bucket))
-            tokens, blps = tokens[:n], blps[:n]
-            last_tokens = last_tokens.at[live].set(tokens)
         with span("decode.sync", self.tracer, rows=n):
+            out = np.asarray(out)
             result = DecodeResult(
                 slots=active,
-                tokens=np.asarray(tokens),
-                logprobs=np.asarray(blps),
-                positions=np.asarray(pos_live),
+                tokens=out[0],
+                logprobs=out[1].view(np.float32),
+                positions=out[2],
             )
         return cache, last_tokens, result

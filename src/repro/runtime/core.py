@@ -748,6 +748,7 @@ class RuntimeCore:
         "decode_steps",
         "prefill_tokens",
         "reprefill_tokens",
+        "decode_device_calls",
         "decode_tokens",
         "preemptions",
         "shared_prefix_hits",

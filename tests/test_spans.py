@@ -177,8 +177,11 @@ def job():
 def test_rollout_programs_lower_under_fixed_names(job):
     _, lowered = job
     names = {"rollout_prefill", "rollout_paged_scatter",
-             "rollout_prefill_sample", "rollout_decode", "rollout_sample"}
+             "rollout_prefill_sample", "rollout_decode"}
     assert {f"jit({n})" for n in names} <= set(lowered)
+    # the paged decode step samples inside rollout_decode; the separate
+    # sampler program is the dense path's only
+    assert "jit(rollout_sample)" not in lowered
     # what the names replace: jit's defaults for a local step function
     # and a lambda
     assert not {"jit(step)", "jit(<lambda>)"} & set(lowered)
