@@ -1,8 +1,10 @@
 """The comparison that decides ``correct``.
 
 Once the window has closed and the program's state is freed, the plain
-reference (``chipbench.reference``) follows the job's first training
-steps from the seed and scores every trajectory those steps consumed.
+reference (``chipbench.reference``, with the model equations the
+configuration's ``"reference"`` key names in ``chipbench/refmodels``)
+follows the job's first training steps from the seed and scores every
+trajectory those steps consumed.
 Each number below is compared with its limit in
 ``chipbench/limits/<workload>.json``, which names the numbers compared;
 ``PERF.md`` gives the readings each limit was set from.

@@ -33,10 +33,16 @@ BENCH = Path(__file__).resolve().parent
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_DIR = ROOT / ".chipbench_trace"
 
-# configuration keys -> the program's ArchConfig fields
+# published configuration keys -> the program's ArchConfig fields. Applied
+# in this order, so an expert model's ``moe_intermediate_size`` wins over
+# its dense ``intermediate_size``. ``num_experts`` is left out: the router's
+# width is published, the experts held here are the file's ``program``
+# block's to say.
 ARCH_FIELDS = {
     "hidden_size": "d_model",
     "intermediate_size": "d_ff",
+    "moe_intermediate_size": "d_ff",
+    "num_experts_per_tok": "top_k",
     "num_attention_heads": "n_heads",
     "num_key_value_heads": "n_kv_heads",
     "head_dim": "head_dim",
@@ -60,17 +66,28 @@ TRACE_STEPS = 3
 
 
 def load_config(name: str, directory: Path = BENCH / "configs"):
-    """(the program's ArchConfig, the file's dict) for a configuration."""
+    """(the program's ArchConfig, the file's dict) for a configuration.
+
+    The fields start from the program's entry ``repro_arch``, take the
+    file's published keys through ``ARCH_FIELDS``, then its ``"program"``
+    block, which names ArchConfig fields directly (what no published key
+    names one-to-one). A ``program`` field ArchConfig lacks, and a missing
+    or unknown ``reference`` model, are errors."""
     import dataclasses
 
+    from chipbench import refmodels
     from repro.configs import get_arch
 
     conf = json.loads((directory / f"{name}.json").read_text())
+    refmodels.load(conf)
     base = get_arch(conf["repro_arch"])
-    cfg = dataclasses.replace(
-        base, **{f: conf[k] for k, f in ARCH_FIELDS.items() if k in conf}
-    )
-    return cfg, conf
+    program = conf.get("program", {})
+    unknown = sorted(set(program) - {f.name for f in dataclasses.fields(base)})
+    if unknown:
+        raise ValueError(f"{name}: program fields {unknown} are not fields "
+                         "of the program's ArchConfig")
+    fields = {f: conf[k] for k, f in ARCH_FIELDS.items() if k in conf}
+    return dataclasses.replace(base, **{**fields, **program}), conf
 
 
 def load_limits(workload: str) -> Dict[str, float]:

@@ -1,21 +1,13 @@
-"""The plain reference: a dense GQA transformer, its DAPO loss and AdamW,
-in straightforward ``jax.numpy``.
+"""The plain reference's RL side: the DAPO loss and AdamW, and a run that
+follows the job's first training steps, in straightforward ``jax.numpy``.
 
-It imports nothing of the program and takes nothing the program made. Its
-weights come from the seed by the same draws the program's initializer
-makes (a seeded normal per matrix, scaled by 1/sqrt(fan in); norms 1,
-biases 0), so at version 0 both hold the same numbers without one handing
-them to the other; after that the reference follows the training steps
-itself, from the token batches the job consumed.
-
-The layer equations, as the configuration file states them: token
-embedding (tied LM head); per layer pre-RMSNorm, q/k/v projections (with
-bias where ``qkv_bias``), rotary embedding on q and k (half-split
-rotation, base ``rope_theta``), causal softmax attention with grouped K/V
-heads and scale 1/sqrt(head_dim), output projection, residual;
-pre-RMSNorm SwiGLU MLP, residual; final RMSNorm and the LM head. The
-vocabulary is padded to a multiple of 256 rows and the padded logit
-columns are masked, as in the program.
+The model equations are the configuration's own: ``follow`` loads the
+module its ``"reference"`` key names (``chipbench/refmodels``). Nothing
+here imports the program or takes what it made. The weights come from the
+seed by the same draws the program's initializer makes, so at version 0
+both hold the same numbers without one handing them to the other; after
+that the reference follows the training steps itself, from the token
+batches the job consumed.
 
 ``dtype`` and ``precision`` pick how it computes. The reference is
 float32 at the matmul precision the configuration states; on a TPU,
@@ -26,154 +18,28 @@ products alone.
 """
 from __future__ import annotations
 
-import dataclasses
-import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import refmodels
+
 Params = Dict[str, Any]
-
-
-@dataclass(frozen=True)
-class Arch:
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab_size: int
-    n_layers: int
-    rope_theta: float
-    norm_eps: float
-    qkv_bias: bool
-    # precision of the attention products (q.k and p.v); None follows the
-    # ambient matmul precision
-    attn_precision: Optional[str] = None
-
-    @classmethod
-    def from_config(cls, conf: Dict[str, Any]) -> "Arch":
-        if not conf.get("tie_word_embeddings", False):
-            raise ValueError("the reference covers tied embeddings only")
-        return cls(
-            d_model=conf["hidden_size"], n_heads=conf["num_attention_heads"],
-            n_kv_heads=conf["num_key_value_heads"], head_dim=conf["head_dim"],
-            d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-            n_layers=conf["num_hidden_layers"], rope_theta=conf["rope_theta"],
-            norm_eps=conf["rms_norm_eps"], qkv_bias=conf["qkv_bias"],
-        )
-
-    @property
-    def padded_vocab(self) -> int:
-        return -(-self.vocab_size // 256) * 256
-
-
-# ------------------------------------------------------------------ weights
-def _normal(key, shape, fan_in):
-    return jax.random.normal(key, shape) * (1.0 / math.sqrt(fan_in))
-
-
-def _layer(a: Arch, key) -> Params:
-    k_norm1, k_attn, k_norm2, k_ffn = jax.random.split(key, 4)
-    d, h, hkv, hd, f = a.d_model, a.n_heads, a.n_kv_heads, a.head_dim, a.d_ff
-    kq, kk, kv, ko = jax.random.split(k_attn, 4)
-    kg, ku, kd = jax.random.split(k_ffn, 3)
-    p = {
-        "attn_norm": jnp.ones((d,)),
-        "wq": _normal(kq, (d, h * hd), d),
-        "wk": _normal(kk, (d, hkv * hd), d),
-        "wv": _normal(kv, (d, hkv * hd), d),
-        "wo": _normal(ko, (h * hd, d), h * hd),
-        "ffn_norm": jnp.ones((d,)),
-        "w_gate": _normal(kg, (d, f), d),
-        "w_up": _normal(ku, (d, f), d),
-        "w_down": _normal(kd, (f, d), f),
-    }
-    if a.qkv_bias:
-        p["bq"] = jnp.zeros((h * hd,))
-        p["bk"] = jnp.zeros((hkv * hd,))
-        p["bv"] = jnp.zeros((hkv * hd,))
-    return p
-
-
-@partial(jax.jit, static_argnums=0)
-def init_params(a: Arch, seed_key) -> Params:
-    """Float32 weights from the seed's key, made on the device in one call."""
-    ks = jax.random.split(seed_key, 8)
-    layer_keys = jnp.stack(jax.random.split(ks[3], a.n_layers))
-    return {
-        "embed": _normal(ks[0], (a.padded_vocab, a.d_model), a.d_model),
-        "final_norm": jnp.ones((a.d_model,)),
-        "blocks": jax.vmap(partial(_layer, a))(layer_keys),
-    }
+# (params, (B, S) tokens) -> (B, S, padded vocab) float32 logits
+Forward = Callable[[Params, Any], Any]
 
 
 def seed_key(seed: int) -> jax.Array:
     return jax.random.PRNGKey(seed)
 
 
-# ------------------------------------------------------------------ forward
-def _rms_norm(x, w, eps):
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x, theta):
-    """x: (B, S, H, hd), positions 0..S-1, half-split rotation."""
-    s, hd = x.shape[1], x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs      # (S, hd/2)
-    sin, cos = jnp.sin(ang)[None, :, None], jnp.cos(ang)[None, :, None]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-    return out.astype(x.dtype)
-
-
-def _block(a: Arch, x, p):
-    b, s, _ = x.shape
-    h, hkv, hd = a.n_heads, a.n_kv_heads, a.head_dim
-    mm = lambda u, w: jnp.einsum("bsd,de->bse", u, w)
-    y = _rms_norm(x, p["attn_norm"], a.norm_eps)
-    q, k, v = mm(y, p["wq"]), mm(y, p["wk"]), mm(y, p["wv"])
-    if a.qkv_bias:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = _rope(q.reshape(b, s, h, hd), a.rope_theta)
-    k = _rope(k.reshape(b, s, hkv, hd), a.rope_theta)
-    v = v.reshape(b, s, hkv, hd)
-    k = jnp.repeat(k, h // hkv, axis=2)
-    v = jnp.repeat(v, h // hkv, axis=2)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        precision=a.attn_precision).astype(jnp.float32)
-    scores = scores / math.sqrt(hd)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(causal[None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    o = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
-                   precision=a.attn_precision).reshape(b, s, h * hd)
-    x = x + mm(o, p["wo"])
-    y = _rms_norm(x, p["ffn_norm"], a.norm_eps)
-    return x + mm(jax.nn.silu(mm(y, p["w_gate"])) * mm(y, p["w_up"]), p["w_down"])
-
-
-def logits(a: Arch, params: Params, tokens):
-    """(B, S) tokens -> (B, S, padded vocab) float32 logits."""
-    x = params["embed"][tokens]
-    for i in range(a.n_layers):
-        x = _block(a, x, jax.tree_util.tree_map(lambda l: l[i], params["blocks"]))
-    x = _rms_norm(x, params["final_norm"], a.norm_eps)
-    out = jnp.einsum("bsd,vd->bsv", x, params["embed"]).astype(jnp.float32)
-    col = jnp.arange(out.shape[-1])
-    return jnp.where(col < a.vocab_size, out, -1e9)
-
-
-def token_logprobs(a: Arch, params: Params, tokens):
+def token_logprobs(fwd: Forward, params: Params, tokens):
     """log p(tokens[:, t+1] | tokens[:, :t+1]) for t < S-1: (B, S-1)."""
-    lg = logits(a, params, tokens)[:, :-1]
+    lg = fwd(params, tokens)[:, :-1]
     tgt = jnp.take_along_axis(lg, tokens[:, 1:, None], axis=-1)[..., 0]
     return tgt - jax.scipy.special.logsumexp(lg, axis=-1)
 
@@ -190,10 +56,10 @@ class Optim:
     eps_high: float = 0.28
 
 
-def dapo_loss(a: Arch, o: Optim, params, batch):
+def dapo_loss(fwd: Forward, o: Optim, params, batch):
     """Token-mean clipped policy gradient over the response tokens, and the
     mean importance ratio against the behaviour logprobs."""
-    lp = token_logprobs(a, params, batch["tokens"])
+    lp = token_logprobs(fwd, params, batch["tokens"])
     blp = batch["behavior_logprobs"][:, 1:]
     m = batch["mask"][:, 1:]
     adv = batch["advantages"][:, None]
@@ -204,12 +70,13 @@ def dapo_loss(a: Arch, o: Optim, params, batch):
     return -(obj * m).sum() / denom, (ratio * m).sum() / denom
 
 
-def train_step(a: Arch, o: Optim, params, state, batch, dtype=jnp.float32):
+def train_step(fwd: Forward, o: Optim, params, state, batch,
+               dtype=jnp.float32):
     """One DAPO step with global-norm clipping and AdamW (no decay) on
     float32 weights; the forward and backward passes run in ``dtype``.
     Returns (params, state, loss, mean ratio, clipped gradient)."""
     (loss, ratio), g = jax.value_and_grad(
-        lambda p: dapo_loss(a, o, _cast(p, dtype), batch), has_aux=True
+        lambda p: dapo_loss(fwd, o, _cast(p, dtype), batch), has_aux=True
     )(params)
     gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
                          for x in jax.tree_util.tree_leaves(g)))
@@ -320,19 +187,20 @@ def follow(conf: Dict[str, Any], seed: int, lr: float,
     tokens and ``batch_rows`` rows, and every scored block to ``rows`` x
     ``width``, so that each program compiles once.
     """
-    a = dataclasses.replace(Arch.from_config(conf),
-                            attn_precision=attn_precision)
+    model = refmodels.load(conf)
+    a = model.from_config(conf, attn_precision)
+    fwd = partial(model.logits, a)
     o = Optim(lr=lr)
     with jax.default_matmul_precision(precision):
-        params = init_params(a, seed_key(seed))
+        params = model.init_params(a, seed_key(seed))
         state = {
             "m": jax.tree_util.tree_map(jnp.zeros_like, params),
             "v": jax.tree_util.tree_map(jnp.zeros_like, params),
             "step": jnp.zeros((), jnp.int32),
         }
         init_host = jax.device_get(params)
-        step_fn = jax.jit(lambda p, s, b: train_step(a, o, p, s, b, dtype))
-        lp_fn = jax.jit(lambda p, t: token_logprobs(a, p, t))
+        step_fn = jax.jit(lambda p, s, b: train_step(fwd, o, p, s, b, dtype))
+        lp_fn = jax.jit(lambda p, t: token_logprobs(fwd, p, t))
         by_version: Dict[int, List[Dict[str, Any]]] = {}
         for t in rollouts:
             for v in {v for v, _ in t["segments"]}:

@@ -17,11 +17,13 @@ LIMITS = json.loads(
 )
 
 
-def run(seed, fault=None, control=False, warm=True, keep=None):
+def run(seed, fault=None, control=False, warm=True, keep=None,
+        config="tiny"):
     from chipbench import harness
 
     return harness.run_cell(
-        CELL, {"per_layer": []}, seed=seed, seconds=1.0, trace=False,
+        dict(CELL, config=config), {"per_layer": []}, seed=seed,
+        seconds=1.0, trace=False,
         t_start=time.perf_counter(), config_dir=DATA, traffic_dir=DATA,
         limits={k: v["limit"] for k, v in LIMITS.items()}, fault=fault,
         control=control, warm=warm, keep=keep,
@@ -58,6 +60,15 @@ def test_control_readings_stand_in_the_programs_place():
 def test_broken_timed_path_is_not_correct(fault):
     res = run(11, fault=fault)
     assert not res.correct, res.checks
+
+
+@pytest.mark.parametrize("fault", [None, "token"])
+def test_untied_head_checked_by_the_files_reference(fault):
+    """A configuration with an untied LM head runs end to end through the
+    reference model its file names: sound, ``correct`` is true; with a
+    token altered where it is produced, false."""
+    res = run(2**32 + 5, fault=fault, config="tiny-untied")
+    assert res.correct == (fault is None), res.checks
 
 
 def test_warming_the_shapes_changes_no_compared_number():

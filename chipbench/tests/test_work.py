@@ -52,6 +52,52 @@ def test_qwen_forward_flops_by_hand():
     assert work.forward_flops(s, 7) == expect
 
 
+def _old_forward_flops(conf, context):
+    """The count before expert models: one dense SwiGLU of
+    ``intermediate_size`` a layer."""
+    d, h, hkv = (conf[k] for k in ("hidden_size", "num_attention_heads",
+                                   "num_key_value_heads"))
+    hd, f, n = conf["head_dim"], conf["intermediate_size"], conf["num_hidden_layers"]
+    layer = d * (h * hd) + 2 * d * (hkv * hd) + (h * hd) * d + 3 * d * f
+    return 2.0 * (n * layer + d * conf["vocab_size"]) + 4.0 * n * h * hd * context
+
+
+def test_qwen_flops_equal_the_dense_formula_exactly():
+    conf = json.loads((CONFIGS / "qwen2-1.5b.json").read_text())
+    s = work.Shape.from_config(conf)
+    for c in (1, 12, 57, 128):
+        assert work.forward_flops(s, c) == _old_forward_flops(conf, c)
+    p, rs = 12, [1, 22, 116]
+    fwd = sum(_old_forward_flops(conf, c) for c in range(1, p + 1))
+    dec = sum(_old_forward_flops(conf, p + j) for r in rs for j in range(1, r))
+    train = 3.0 * sum(fwd + sum(_old_forward_flops(conf, p + j)
+                                for j in range(1, r + 1)) for r in rs)
+    assert work.job_flops(s, p, rs) == fwd + dec + train
+
+
+def test_expert_forward_flops_by_hand():
+    """4 of 16 experts held, top-2: a token's 2 slots land here 2 * 4 / 16
+    = 0.5 times under uniform routing, beside the router over all 16."""
+    conf = {
+        "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
+        "head_dim": 16, "intermediate_size": 6144, "moe_intermediate_size": 32,
+        "num_experts": 4, "num_experts_per_tok": 2, "vocab_size": 1000,
+        "num_hidden_layers": 2,
+        "reduced": {"num_experts": {"published": 16, "here": 4}},
+    }
+    s = work.Shape.from_config(conf)
+    assert (s.d_ff, s.n_experts, s.experts_total, s.top_k) == (32, 4, 16, 2)
+    # attention 64*64 + 2*64*32 + 64*64 = 12288; router 64*16 = 1024;
+    # experts 0.5 * 3*64*32 = 3072
+    assert s.layer_matmul_params == 16384
+    # 2 * (2 layers * 16384 + head 64*1000) + attention 4*2*4*16*5
+    assert work.forward_flops(s, 5) == 196096.0
+    # held experts alone, uncut: every slot lands here
+    del conf["reduced"]
+    whole = work.Shape.from_config(conf)
+    assert whole.layer_matmul_params == 12288 + 64 * 4 + 2 * 3 * 64 * 32
+
+
 def test_job_flops_counts_prompt_once_and_each_token_once():
     s = shape("granite-3-8b")
     one = work.forward_flops

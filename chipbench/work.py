@@ -42,23 +42,48 @@ class Shape:
     n_heads: int
     n_kv_heads: int
     head_dim: int
-    d_ff: int
+    d_ff: int                 # the dense FFN's width, or one expert's
     vocab: int
     n_layers: int
     dtype_bytes: int = 4
+    n_experts: int = 0        # experts held on this chip; 0: a dense FFN
+    experts_total: int = 0    # the router's width
+    top_k: int = 0            # experts per token
 
     @classmethod
     def from_config(cls, conf: Dict) -> "Shape":
+        """The file's shape. An expert model (``num_experts``, the count
+        held here) routes over ``reduced.num_experts.published`` experts
+        where the file cut them, else over those held, to
+        ``num_experts_per_tok`` of width ``moe_intermediate_size``."""
+        held = conf.get("num_experts", 0)
+        experts = {}
+        if held:
+            experts = dict(
+                n_experts=held, top_k=conf["num_experts_per_tok"],
+                experts_total=conf.get("reduced", {}).get(
+                    "num_experts", {}).get("published", held))
         return cls(conf["hidden_size"], conf["num_attention_heads"],
                    conf["num_key_value_heads"], conf["head_dim"],
-                   conf["intermediate_size"], conf["vocab_size"],
-                   conf["num_hidden_layers"])
+                   conf["moe_intermediate_size" if held else
+                        "intermediate_size"], conf["vocab_size"],
+                   conf["num_hidden_layers"], **experts)
 
     @property
-    def layer_matmul_params(self) -> int:
+    def layer_matmul_params(self) -> float:
+        """Weights one token multiplies by in one layer: the attention
+        projections, and the dense SwiGLU FFN, or the router and the
+        expected expert slots on this chip. Under uniform routing a token's
+        ``top_k`` slots land here ``top_k * n_experts / experts_total``
+        times, each a SwiGLU of width ``d_ff``; what the tokens actually
+        routed here need is not counted."""
         d, qd = self.d_model, self.n_heads * self.head_dim
         kvd = self.n_kv_heads * self.head_dim
-        return d * qd + 2 * d * kvd + qd * d + 3 * d * self.d_ff
+        attn = d * qd + 2 * d * kvd + qd * d
+        if not self.n_experts:
+            return attn + 3 * d * self.d_ff
+        slots = self.top_k * self.n_experts / self.experts_total
+        return attn + d * self.experts_total + slots * 3 * d * self.d_ff
 
 
 def paged_decode(s: Shape, lengths: Sequence[int]) -> Tuple[float, float]:
@@ -92,7 +117,9 @@ def prefill_attention(s: Shape, rows: Iterable[Tuple[int, int]]
 
 def forward_flops(s: Shape, context: int) -> float:
     """Operations of one token's forward pass at ``context`` positions
-    (itself included): every matmul, the LM head, and causal attention."""
+    (itself included): every matmul (``Shape.layer_matmul_params``), the
+    LM head over the file's vocabulary (tied or not, the same
+    operations), and causal attention."""
     return (2.0 * (s.n_layers * s.layer_matmul_params + s.d_model * s.vocab)
             + 4.0 * s.n_layers * s.n_heads * s.head_dim * context)
 
